@@ -25,7 +25,7 @@ import numpy as np
 from repro.core import (FaultInjectingKVS, InMemoryKVS, RecoveryManager,
                         ReplicatedKVS, RStore, RStoreConfig, ShardedKVS)
 
-from .common import emit, save_json
+from .common import emit, main, save_json
 
 N_SHARDS = 4
 N_SESSIONS = 8
@@ -226,4 +226,4 @@ def run(smoke: bool = False):
 
 
 if __name__ == "__main__":
-    run()
+    main(run)

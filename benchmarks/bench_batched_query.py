@@ -21,7 +21,7 @@ import numpy as np
 from repro.core import DatasetSpec, Q, RStore, RStoreConfig, generate
 from repro.core.kvs import KVSStats
 
-from .common import emit, save_json
+from .common import emit, main, save_json
 
 SPEC = DatasetSpec(n_versions=120, n_base_records=600, pct_update=0.1,
                    record_size=512, payloads=True, p_d=0.05,
@@ -125,4 +125,4 @@ def run(smoke: bool = False):
 
 
 if __name__ == "__main__":
-    run()
+    main(run)

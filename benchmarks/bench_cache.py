@@ -26,7 +26,7 @@ from repro.core import (CachingKVS, InMemoryKVS, KVSStats, Q, RStore,
                         RStoreConfig, ShardedKVS, keep_last)
 from repro.core.costmodel import BANDWIDTH_BPS, PER_QUERY_S
 
-from .common import emit, save_json
+from .common import emit, main, save_json
 
 N_SHARDS = 4
 CACHE_BYTES = 64 << 20
@@ -169,4 +169,4 @@ def run(smoke: bool = False):
 
 
 if __name__ == "__main__":
-    run()
+    main(run)

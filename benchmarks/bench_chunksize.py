@@ -13,7 +13,7 @@ import numpy as np
 from repro.core import DatasetSpec, generate
 from repro.core.kvs import InMemoryKVS, ShardedDeviceKVS
 
-from .common import emit, save_json, timed
+from .common import emit, main, save_json, timed
 
 
 def run():
@@ -55,4 +55,4 @@ def run():
 
 
 if __name__ == "__main__":
-    run()
+    main(run)

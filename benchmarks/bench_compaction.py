@@ -23,7 +23,7 @@ import numpy as np
 from repro.core import (InMemoryKVS, KVSStats, Q, RStore, RStoreConfig,
                         ShardedKVS, keep_last, measure_layout)
 
-from .common import emit, save_json
+from .common import emit, main, save_json
 
 N_SHARDS = 4
 PER_QUERY_S = 5e-4
@@ -157,4 +157,4 @@ def run(smoke: bool = False):
 
 
 if __name__ == "__main__":
-    run()
+    main(run)

@@ -26,7 +26,7 @@ from repro.core import (FaultInjectingKVS, InMemoryKVS, KVSStats, Q,
                         RecoveryManager, ReplicatedKVS, RStore, RStoreConfig,
                         ShardedKVS)
 
-from .common import emit, save_json
+from .common import emit, main, save_json
 
 N_SHARDS = 4
 R = 2
@@ -198,4 +198,4 @@ def run(smoke: bool = False):
 
 
 if __name__ == "__main__":
-    run()
+    main(run)

@@ -15,7 +15,7 @@ from repro.core.partition import BottomUpPartitioner
 from repro.core.subchunk import (build_subchunks, build_transformed,
                                  compressed_subchunk_sizes)
 
-from .common import emit, save_json
+from .common import emit, main, save_json
 
 CAPACITY = 32 * 1024
 
@@ -49,4 +49,4 @@ def run():
 
 
 if __name__ == "__main__":
-    run()
+    main(run)

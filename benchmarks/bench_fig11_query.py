@@ -18,7 +18,7 @@ import numpy as np
 
 from repro.core import DatasetSpec, Q, RStore, RStoreConfig, generate
 
-from .common import emit, save_json
+from .common import emit, main, save_json
 
 SPEC = DatasetSpec(n_versions=100, n_base_records=500, pct_update=0.1,
                    record_size=512, payloads=True, p_d=0.05,
@@ -103,4 +103,4 @@ def run():
 
 
 if __name__ == "__main__":
-    run()
+    main(run)

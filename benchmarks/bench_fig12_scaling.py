@@ -1,76 +1,61 @@
 """Fig. 12: weak scalability — double the devices AND the data, measure Q1/Q3.
 
 The paper doubles a Cassandra cluster 1→16 nodes while doubling versions; we
-shard the ShardedDeviceKVS over 1→16 host devices (separate subprocess so the
-device count can differ from the dry-run's 512) and scale the version count
-with the device count.  Claim: query times grow mildly (span growth), i.e.
-weak scaling holds.
+shard the ShardedDeviceKVS over sub-meshes of 1→16 of the process's own
+devices (as many as it has) and scale the version count with the device
+count.  Claim: query times grow mildly (span growth), i.e. weak scaling
+holds.  Every point runs in this process; a failed point fails the suite.
 """
 from __future__ import annotations
 
-import json
-import os
-import pathlib
-import subprocess
-import sys
+import time
 
-from .common import emit, save_json
-
-_CHILD = r"""
-import os, sys, json, time
-os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={sys.argv[1]}"
-sys.path.insert(0, "src")
-import numpy as np
 import jax
+import numpy as np
+from jax.sharding import Mesh
+
 from repro.core import DatasetSpec, RStore, RStoreConfig, generate
 from repro.core.kvs import ShardedDeviceKVS
 
-ndev = int(sys.argv[1])
-base_versions = 40
-spec = DatasetSpec(n_versions=base_versions * ndev, n_base_records=400,
-                   pct_update=0.1, record_size=256, payloads=True,
-                   branch_prob=0.05, seed=21)
-g = generate(spec)
-mesh = jax.make_mesh((ndev,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
-kvs = ShardedDeviceKVS(slot_bytes=32 * 1024, n_slots=256, mesh=mesh)
-rs = RStore(RStoreConfig(algorithm="bottom_up", capacity=24 * 1024,
-                         batch_size=10**9), kvs=kvs)
-rs.graph = g
-rs._grow_r2c()
-rs.build()
+from .common import emit, main, save_json
 
-rng = np.random.default_rng(0)
-vids = rng.choice(g.versions, 8)
-keys = rng.choice(g.store.keys(), 8)
-# warmup (compile the gather)
-rs.get_version(int(vids[0]))
-t0 = time.perf_counter(); spans = []
-for v in vids:
-    _, st = rs.get_version(int(v)); spans.append(st.chunks_fetched)
-q1 = (time.perf_counter() - t0) / len(vids)
-t0 = time.perf_counter(); kspans = []
-for k in keys:
-    _, st = rs.get_evolution(int(k)); kspans.append(st.chunks_fetched)
-q3 = (time.perf_counter() - t0) / len(keys)
-print(json.dumps({"ndev": ndev, "versions": spec.n_versions,
-                  "q1_s": q1, "q3_s": q3,
-                  "avg_version_span": float(np.mean(spans)),
-                  "avg_key_span": float(np.mean(kspans))}))
-"""
+
+def _point(devices) -> dict:
+    ndev = len(devices)
+    spec = DatasetSpec(n_versions=40 * ndev, n_base_records=400,
+                       pct_update=0.1, record_size=256, payloads=True,
+                       branch_prob=0.05, seed=21)
+    g = generate(spec)
+    kvs = ShardedDeviceKVS(slot_bytes=32 * 1024, n_slots=256,
+                           mesh=Mesh(np.asarray(devices), ("data",)))
+    rs = RStore(RStoreConfig(algorithm="bottom_up", capacity=24 * 1024,
+                             batch_size=10**9), kvs=kvs)
+    rs.graph = g
+    rs._grow_r2c()
+    rs.build()
+
+    rng = np.random.default_rng(0)
+    vids = rng.choice(g.versions, 8)
+    keys = rng.choice(g.store.keys(), 8)
+    rs.get_version(int(vids[0]))          # warmup (compile the gather)
+    t0 = time.perf_counter()
+    spans = [rs.get_version(int(v))[1].chunks_fetched for v in vids]
+    q1 = (time.perf_counter() - t0) / len(vids)
+    t0 = time.perf_counter()
+    kspans = [rs.get_evolution(int(k))[1].chunks_fetched for k in keys]
+    q3 = (time.perf_counter() - t0) / len(keys)
+    return {"ndev": ndev, "versions": spec.n_versions, "q1_s": q1,
+            "q3_s": q3, "avg_version_span": float(np.mean(spans)),
+            "avg_key_span": float(np.mean(kspans))}
 
 
 def run():
+    devices = jax.devices()
     out = {}
     for ndev in (1, 2, 4, 8, 16):
-        proc = subprocess.run(
-            [sys.executable, "-c", _CHILD, str(ndev)],
-            capture_output=True, text=True, timeout=900,
-            cwd=pathlib.Path(__file__).resolve().parents[1])
-        if proc.returncode != 0:
-            emit(f"fig12/ndev{ndev}", 0.0, f"ERROR {proc.stderr[-200:]}")
-            continue
-        rec = json.loads(proc.stdout.strip().splitlines()[-1])
+        if ndev > len(devices):
+            break
+        rec = _point(devices[:ndev])
         out[ndev] = rec
         emit(f"fig12/ndev{ndev}", rec["q1_s"] * 1e6,
              f"versions={rec['versions']} vspan={rec['avg_version_span']:.1f} "
@@ -80,4 +65,4 @@ def run():
 
 
 if __name__ == "__main__":
-    run()
+    main(run)

@@ -12,7 +12,7 @@ import numpy as np
 from repro.core import DatasetSpec, RStore, RStoreConfig, generate
 from repro.core.partition import BottomUpPartitioner, total_version_span
 
-from .common import emit, save_json
+from .common import emit, main, save_json
 
 CAPACITY = 16 * 1024
 
@@ -63,4 +63,4 @@ def run():
 
 
 if __name__ == "__main__":
-    run()
+    main(run)

@@ -14,7 +14,7 @@ from repro.core import PAPER_DATASETS, generate
 from repro.core.partition import (ALGORITHMS, DeltaBaseline,
                                   total_version_span)
 
-from .common import emit, save_json
+from .common import emit, main, save_json
 
 ALGOS = ["bottom_up", "shingle", "depth_first", "breadth_first"]
 CAPACITY = 64 * 1024          # ~1 MB in the paper; scaled with record count
@@ -50,4 +50,4 @@ def run(datasets=None):
 
 
 if __name__ == "__main__":
-    run()
+    main(run)

@@ -10,7 +10,7 @@ import time
 from repro.core import PAPER_DATASETS, generate
 from repro.core.partition import BottomUpPartitioner, total_version_span
 
-from .common import emit, save_json
+from .common import emit, main, save_json
 
 CAPACITY = 64 * 1024
 
@@ -30,4 +30,4 @@ def run():
 
 
 if __name__ == "__main__":
-    run()
+    main(run)

@@ -24,7 +24,7 @@ from repro.core.costmodel import BANDWIDTH_BPS, PER_QUERY_S
 from repro.core.secondary import datagen_extractor
 from repro.kernels import ops
 
-from .common import emit, save_json
+from .common import emit, main, save_json
 
 N_SHARDS = 2
 A0, A1 = "f0", "f1"               # two uint32 attrs of the datagen layout
@@ -157,4 +157,4 @@ def run(smoke: bool = False):
 
 
 if __name__ == "__main__":
-    run()
+    main(run)

@@ -27,7 +27,7 @@ from repro.core import (CachingKVS, InMemoryKVS, KVSStats, Q, RStore,
 from repro.core.costmodel import BANDWIDTH_BPS, PER_QUERY_S
 from repro.core.secondary import datagen_extractor
 
-from .common import emit, save_json
+from .common import emit, main, save_json
 
 N_SHARDS = 2
 ATTR = "f0"                       # first uint32 of the datagen attr layout
@@ -151,4 +151,4 @@ def run(smoke: bool = False):
 
 
 if __name__ == "__main__":
-    run()
+    main(run)

@@ -14,7 +14,7 @@ from repro.core.partition import (ALGORITHMS, DeltaBaseline,
                                   SubChunkPartitioner, total_version_span,
                                   version_spans)
 
-from .common import emit, save_json
+from .common import emit, main, save_json
 
 N, M, D, S = 60, 400, 0.10, 256
 CAP = 8 * 1024
@@ -82,4 +82,4 @@ def run():
 
 
 if __name__ == "__main__":
-    run()
+    main(run)

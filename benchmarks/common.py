@@ -27,6 +27,13 @@ def timed(fn: Callable, *args, repeat: int = 1, **kw):
     return out, dt
 
 
+def main(run: Callable) -> None:
+    """A benchmark module's entry point: place the compile cache, then run."""
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    run()
+
+
 def save_json(name: str, payload) -> None:
     (RESULTS / f"{name}.json").write_text(json.dumps(payload, indent=2))
 

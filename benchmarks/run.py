@@ -52,6 +52,8 @@ def _jsonable(obj):
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (bench_async_ingest, bench_batched_query, bench_cache,
                    bench_chunksize, bench_compaction, bench_fault_tolerance,
                    bench_fig8_span, bench_fig9_beta, bench_fig10_compression,

@@ -455,6 +455,11 @@ class ShardedDeviceKVS:
             self._dirty = False
         return self._table
 
+    @property
+    def devices(self) -> set:
+        """The devices the slot table is placed on (syncs it first)."""
+        return set(self._sync().devices())
+
     # ------------------------------------------------------------------ get
     def multiget(self, keys: Sequence[str]) -> List[bytes]:
         if not keys:                      # empty batch: no gather, no stats

@@ -69,7 +69,19 @@ class RecordStore:
             if c in self._index:
                 raise ValueError(f"record {CompositeKey.from_packed(c)} already exists")
             self._index[c] = base + i
-        self._invalidate()
+        # extend the cached array views rather than dropping them: rebuilding
+        # them from the lists costs O(N) Python-int boxing on every commit
+        cks = np.asarray(cks, dtype=np.int64)
+        if self._cks_arr is not None and len(self._cks_arr) == base:
+            self._cks_arr = np.concatenate([self._cks_arr, cks])
+        else:
+            self._cks_arr = None
+        if self._keys_arr is not None and len(self._keys_arr) == base:
+            self._keys_arr = np.concatenate([self._keys_arr,
+                                             unpack_ck_array(cks)[0]])
+        else:
+            self._keys_arr = None
+        self._sizes_arr = None
         return out
 
     def lookup(self, ck: int) -> Optional[int]:

@@ -20,7 +20,9 @@ and secondary-index bitmaps evaluates in ONE fused launch; the final
 register file and per-row popcounts come back together.  An empty program
 passes the register file through unchanged.  The instruction stream lives in
 SMEM (scalar memory) — its fields drive dynamic row indexing into the VMEM
-register file.
+register file.  Bitmap columns are independent, so the register file is
+tiled along W: each grid step runs the whole program over one lane block and
+the per-row popcounts accumulate across blocks.
 
 Popcount uses the SWAR bit-twiddle (no LUT: TPU VPU has no gather), entirely
 in uint32 lanes.
@@ -96,26 +98,45 @@ def and_popcount(bitmaps: jax.Array, row: jax.Array,
 
 
 # ------------------------------------------------------------------ bitmap VM
+# VMEM budget for one (S, block_w) register-file tile; the input and output
+# tiles are each double-buffered, so the kernel holds ~4x this
+_VM_TILE_BYTES = 2 << 20
+
+
+def _lane_block(width: int, rows: int, tile_bytes: int) -> int:
+    """Widest multiple of 128 lanes that divides ``width`` and keeps a
+    ``(rows, block)`` uint32 tile within ``tile_bytes`` (at least 128)."""
+    best = 128
+    for b in range(128, width + 1, 128):
+        if width % b == 0 and rows * b * 4 <= tile_bytes:
+            best = b
+    return best
+
+
 def _bitmap_vm_kernel(prog_ref, regs_ref, out_ref, cnt_ref):
-    # copy the register file, then execute the program in place: every
-    # instruction reads/writes whole (1, W) rows at dynamic (SMEM-sourced)
-    # sublane offsets
+    # one lane block of the register file: copy it, then execute the whole
+    # program in place; every instruction reads/writes (1, block_w) rows at
+    # dynamic (SMEM-sourced) sublane offsets.  Columns are independent, so
+    # each block runs the same program and popcounts add up across blocks.
     out_ref[...] = regs_ref[...]
 
     def body(i, carry):
         op = prog_ref[i, 0]
         dst = prog_ref[i, 1]
-        lhs = prog_ref[i, 2]
-        rhs = prog_ref[i, 3]
-        a = pl.load(out_ref, (pl.ds(lhs, 1), slice(None)))
-        b = pl.load(out_ref, (pl.ds(rhs, 1), slice(None)))
-        r = jnp.where(op == OP_AND, a & b,
-                      jnp.where(op == OP_OR, a | b, a & ~b))
-        pl.store(out_ref, (pl.ds(dst, 1), slice(None)), r)
+        a = out_ref[pl.ds(prog_ref[i, 2], 1), :]
+        b = out_ref[pl.ds(prog_ref[i, 3], 1), :]
+        out_ref[pl.ds(dst, 1), :] = jnp.where(
+            op == OP_AND, a & b, jnp.where(op == OP_OR, a | b, a & ~b))
         return carry
 
     jax.lax.fori_loop(0, prog_ref.shape[0], body, 0)
-    cnt_ref[0, :] = jnp.sum(_popcount32(out_ref[...]).astype(jnp.int32), axis=1)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+
+    cnt_ref[0, :] += jnp.sum(_popcount32(out_ref[...]).astype(jnp.int32),
+                             axis=1)
 
 
 def bitmap_vm(regs: jax.Array, prog: jax.Array,
@@ -123,7 +144,8 @@ def bitmap_vm(regs: jax.Array, prog: jax.Array,
     """Execute a bitmap program over an (S, W) uint32 register file.
 
     Args:
-      regs: (S, W) uint32 register file (leaf bitmaps + zeroed scratch rows).
+      regs: (S, W) uint32 register file (leaf bitmaps + zeroed scratch rows),
+        S % 128 == 0 and W % 128 == 0 (callers pad).
       prog: (P, 4) int32 instructions ``(opcode, dst, lhs, rhs)`` with
         opcode in {OP_AND, OP_OR, OP_ANDNOT} and row operands in [0, S).
         P == 0 is the empty program (register file passes through).
@@ -139,21 +161,26 @@ def bitmap_vm(regs: jax.Array, prog: jax.Array,
         # static and the empty-program contract explicit
         counts = jnp.sum(_popcount32(regs).astype(jnp.int32), axis=1)
         return regs, counts
+    if W % 128:
+        raise ValueError(f"W={W} must be a multiple of 128")
+    bw = _lane_block(W, S, _VM_TILE_BYTES)
     out, counts = pl.pallas_call(
         _bitmap_vm_kernel,
-        grid=(1,),
+        grid=(W // bw,),
         in_specs=[
-            pl.BlockSpec((P, 4), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((S, W), lambda i: (0, 0)),
+            pl.BlockSpec((P, 4), lambda j: (0, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec((S, bw), lambda j: (0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((S, W), lambda i: (0, 0)),
-            pl.BlockSpec((1, S), lambda i: (0, 0)),
+            pl.BlockSpec((S, bw), lambda j: (0, j)),
+            pl.BlockSpec((1, S), lambda j: (0, 0)),     # accumulated over j
         ],
         out_shape=[
             jax.ShapeDtypeStruct((S, W), jnp.uint32),
             jax.ShapeDtypeStruct((1, S), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(prog, regs)
     return out, counts[0]

@@ -24,6 +24,7 @@ from jax.experimental import pallas as pl
 BLOCK_R = 128
 PAD_VERSION = -1
 _EMPTY_HASH = np.uint32(0xFFFFFFFF)
+_SIGN = np.uint32(0x80000000)
 
 
 def _minhash_kernel(vers_ref, a_ref, b_ref, out_ref, *, n_hashes: int):
@@ -35,7 +36,11 @@ def _minhash_kernel(vers_ref, a_ref, b_ref, out_ref, *, n_hashes: int):
         b = b_ref[0, l]
         hv = a * vu + b                                # uint32 wraparound hash
         hv = jnp.where(valid, hv, _EMPTY_HASH)
-        out_ref[l, :] = jnp.min(hv, axis=1)
+        # the TPU has no unsigned min-reduction: flipping the sign bit maps
+        # uint32 order onto int32 order, so take the min there and map back
+        hs = jax.lax.bitcast_convert_type(hv ^ _SIGN, jnp.int32)
+        m = jax.lax.bitcast_convert_type(jnp.min(hs, axis=1), jnp.uint32)
+        out_ref[l, :] = m ^ _SIGN
 
 
 def minhash(versions_padded: jax.Array, a: jax.Array, b: jax.Array,

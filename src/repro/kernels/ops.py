@@ -3,17 +3,18 @@
 These handle padding/blocking to the kernels' tile contracts and expose
 NumPy-friendly entry points for the host-side partitioners.
 
-Dispatch policy: on TPU the Pallas kernels run compiled; on CPU (this
-container) the *batch* entry points route through the jitted jnp oracles
-(bit-identical — asserted by tests/test_kernels.py, which also exercises the
-kernels under interpret=True), because interpret-mode Pallas executes kernel
-bodies in Python and is orders of magnitude too slow for the multi-million-
-record benchmark workloads.
+Dispatch policy, decided per call from the backend JAX runs on: on TPU the
+Pallas kernels run compiled; on CPU the entry points run their jitted jnp
+twins from :mod:`repro.kernels.ref` (bit-identical — asserted by
+tests/test_kernels.py, which also runs the kernels under interpret=True);
+any other platform raises.  Interpret-mode Pallas executes kernel bodies in
+Python and is far too slow for store-sized batches, so it is never a
+fallback.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -22,8 +23,7 @@ import numpy as np
 from . import bitmap as _bitmap
 from . import deltaenc as _deltaenc
 from . import minhash as _minhash
-
-INTERPRET = jax.default_backend() != "tpu"
+from . import ref
 
 _P_LANE = 128
 
@@ -32,10 +32,25 @@ def _pad_to(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+def _compiled(kernel: Callable) -> Callable:
+    return jax.jit(functools.partial(kernel, interpret=False))
+
+
+def _pick(kernel: Callable, twin: Callable) -> Callable:
+    """The compiled kernel on TPU, its jnp twin on CPU."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return kernel
+    if platform == "cpu":
+        return twin
+    raise RuntimeError(f"no kernel path for JAX platform {platform!r}: "
+                       "the store runs its Pallas kernels on TPU and their "
+                       "jnp twins on CPU")
+
+
 # ------------------------------------------------------------------ minhash
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _minhash_jit(vers, a, b, interpret=INTERPRET):
-    return _minhash.minhash(vers, a, b, interpret=interpret)
+_minhash_kernel = _compiled(_minhash.minhash)
+_minhash_twin = jax.jit(ref.minhash_ref)
 
 
 def hash_family(n_hashes: int, seed: int = 0) -> Tuple[np.ndarray, np.ndarray]:
@@ -46,28 +61,21 @@ def hash_family(n_hashes: int, seed: int = 0) -> Tuple[np.ndarray, np.ndarray]:
     return a, b
 
 
-def minhash_padded(versions_padded: np.ndarray, a: np.ndarray, b: np.ndarray,
-                   *, interpret: bool = INTERPRET) -> np.ndarray:
-    """Pad (R, D) rows to tile boundaries and run the kernel. Returns (R, L)."""
+def minhash_padded(versions_padded: np.ndarray, a: np.ndarray,
+                   b: np.ndarray) -> np.ndarray:
+    """Pad (R, D) rows to tile boundaries and min-hash them. Returns (R, L)."""
     R, D = versions_padded.shape
     Rp = _pad_to(max(R, 1), _minhash.BLOCK_R)
     Dp = _pad_to(max(D, 1), _P_LANE)
     buf = np.full((Rp, Dp), _minhash.PAD_VERSION, dtype=np.int32)
     buf[:R, :D] = versions_padded
-    out = _minhash_jit(jnp.asarray(buf), jnp.asarray(a), jnp.asarray(b),
-                       interpret=interpret)
+    out = _pick(_minhash_kernel, _minhash_twin)(
+        jnp.asarray(buf), jnp.asarray(a), jnp.asarray(b))
     return np.asarray(out)[:, :R].T  # (R, L)
 
 
-@functools.partial(jax.jit, static_argnames=())
-def _minhash_ref_jit(vers, a, b):
-    from . import ref
-    return ref.minhash_ref(vers, a, b)
-
-
-def minhash_csr(indptr: np.ndarray, col: np.ndarray, a: np.ndarray, b: np.ndarray,
-                *, block_rows: int = 8192, interpret: bool = INTERPRET,
-                force_kernel: bool = False) -> np.ndarray:
+def minhash_csr(indptr: np.ndarray, col: np.ndarray, a: np.ndarray,
+                b: np.ndarray, *, block_rows: int = 8192) -> np.ndarray:
     """Min-hash ragged CSR rows.
 
     Rows are processed in blocks; each block is padded to its own max degree
@@ -90,29 +98,13 @@ def minhash_csr(indptr: np.ndarray, col: np.ndarray, a: np.ndarray, b: np.ndarra
         rows = np.repeat(np.arange(hi - lo), deg)
         offs = np.arange(ptr[-1] - ptr[0]) - np.repeat(ptr[:-1] - ptr[0], deg)
         block[rows, offs] = col[ptr[0]:ptr[-1]]
-        if interpret and not force_kernel:
-            # interpret-mode pallas executes the kernel body in Python —
-            # far too slow for multi-million-record host workloads.  Use the
-            # jitted jnp oracle (bit-identical; asserted by the kernel tests)
-            # and reserve the kernel for real-TPU runs / explicit validation.
-            got = np.asarray(_minhash_ref_jit(
-                jnp.asarray(block), jnp.asarray(a), jnp.asarray(b))).T
-        else:
-            got = minhash_padded(block, a, b, interpret=interpret)
-        out[lo:hi] = got
+        out[lo:hi] = minhash_padded(block, a, b)
     return out
 
 
 # ---------------------------------------------------------------- xor delta
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _xor_jit(p, c, interpret=INTERPRET):
-    return _deltaenc.xor_delta(p, c, interpret=interpret)
-
-
-@jax.jit
-def _xor_ref_jit(p, c):
-    from . import ref
-    return ref.xor_delta_ref(p, c)
+_xor_kernel = _compiled(_deltaenc.xor_delta)
+_xor_twin = jax.jit(ref.xor_delta_ref)
 
 
 def _bytes_to_words(buf: bytes, width: int) -> np.ndarray:
@@ -123,8 +115,8 @@ def _bytes_to_words(buf: bytes, width: int) -> np.ndarray:
     return out.view(np.uint32)
 
 
-def xor_delta_batch(parent: np.ndarray, child: np.ndarray,
-                    *, interpret: bool = INTERPRET) -> Tuple[np.ndarray, np.ndarray]:
+def xor_delta_batch(parent: np.ndarray,
+                    child: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     """(N, W) uint32 batches → (delta (N, W), changed_words (N,)). Pads N."""
     N, W = parent.shape
     Np = _pad_to(max(N, 1), _deltaenc.BLOCK_N)
@@ -133,20 +125,16 @@ def xor_delta_batch(parent: np.ndarray, child: np.ndarray,
     cb = np.zeros((Np, Wp), dtype=np.uint32)
     pb[:N, :W] = parent
     cb[:N, :W] = child
-    if interpret:
-        d, cnt = _xor_ref_jit(jnp.asarray(pb), jnp.asarray(cb))
-    else:
-        d, cnt = _xor_jit(jnp.asarray(pb), jnp.asarray(cb), interpret=False)
+    d, cnt = _pick(_xor_kernel, _xor_twin)(jnp.asarray(pb), jnp.asarray(cb))
     return np.asarray(d)[:N, :W], np.asarray(cnt)[:N]
 
 
-def xor_delta_bytes(parent: bytes, child: bytes,
-                    *, interpret: bool = INTERPRET) -> Tuple[bytes, int]:
+def xor_delta_bytes(parent: bytes, child: bytes) -> Tuple[bytes, int]:
     """Delta-encode one payload against its parent (decode is the same call)."""
     w = max(len(parent), len(child))
     pw = _bytes_to_words(parent, w)
     cw = _bytes_to_words(child, w)
-    d, cnt = xor_delta_batch(pw[None, :], cw[None, :], interpret=interpret)
+    d, cnt = xor_delta_batch(pw[None, :], cw[None, :])
     return d[0].tobytes()[:w], int(cnt[0])
 
 
@@ -157,13 +145,12 @@ def xor_delta_bytes(parent: bytes, child: bytes,
 BITMAP_LAUNCHES = 0
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _and_jit(bms, row, interpret=INTERPRET):
-    return _bitmap.and_popcount(bms, row, interpret=interpret)
+_and_kernel = _compiled(_bitmap.and_popcount)
+_and_twin = jax.jit(ref.and_popcount_ref)
 
 
-def and_popcount_batch(bitmaps: np.ndarray, row: np.ndarray,
-                       *, interpret: bool = INTERPRET) -> Tuple[np.ndarray, np.ndarray]:
+def and_popcount_batch(bitmaps: np.ndarray,
+                       row: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     """AND (N, W) bitmaps against a row; returns (anded, popcounts).
 
     ``row`` is a single shared (W,)/(1, W) bitmap (broadcast against every
@@ -186,32 +173,17 @@ def and_popcount_batch(bitmaps: np.ndarray, row: np.ndarray,
     rb = np.zeros((Np if pairwise else 1, Wp), dtype=np.uint32)
     bb[:N, :W] = bitmaps
     rb[:row.shape[0], :W] = row
-    if interpret:
-        anded, cnt = _and_ref_jit(jnp.asarray(bb), jnp.asarray(rb))
-    else:
-        anded, cnt = _and_jit(jnp.asarray(bb), jnp.asarray(rb), interpret=False)
+    anded, cnt = _pick(_and_kernel, _and_twin)(jnp.asarray(bb),
+                                               jnp.asarray(rb))
     return np.asarray(anded)[:N, :W], np.asarray(cnt)[:N]
 
 
-@jax.jit
-def _and_ref_jit(bms, row):
-    from . import ref
-    return ref.and_popcount_ref(bms, row)
+_vm_kernel = _compiled(_bitmap.bitmap_vm)
+_vm_twin = jax.jit(ref.bitmap_vm_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _vm_jit(regs, prog, interpret=INTERPRET):
-    return _bitmap.bitmap_vm(regs, prog, interpret=interpret)
-
-
-@jax.jit
-def _vm_ref_jit(regs, prog):
-    from . import ref
-    return ref.bitmap_vm_ref(regs, prog)
-
-
-def bitmap_vm_batch(regs: np.ndarray, prog: np.ndarray,
-                    *, interpret: bool = INTERPRET) -> Tuple[np.ndarray, np.ndarray]:
+def bitmap_vm_batch(regs: np.ndarray,
+                    prog: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     """Run one bitmap program over an (S, W) uint32 register file.
 
     ``prog`` is (P, 4) int32 ``(opcode, dst, lhs, rhs)`` rows (opcodes
@@ -236,8 +208,10 @@ def bitmap_vm_batch(regs: np.ndarray, prog: np.ndarray,
     pg = np.zeros((Pp, 4), dtype=np.int32)
     pg[:, 0] = _bitmap.OP_OR           # no-op padding: regs[0] |= regs[0]
     pg[:len(prog)] = prog
-    if interpret:
-        out, cnt = _vm_ref_jit(jnp.asarray(rb), jnp.asarray(pg))
-    else:
-        out, cnt = _vm_jit(jnp.asarray(rb), jnp.asarray(pg), interpret=False)
+    out, cnt = _pick(_vm_kernel, _vm_twin)(jnp.asarray(rb), jnp.asarray(pg))
     return np.asarray(out)[:S, :W], np.asarray(cnt)[:S]
+
+
+# the compiled program behind each entry point's TPU path, by kernel name
+KERNELS = {"minhash": _minhash_kernel, "xor_delta": _xor_kernel,
+           "and_popcount": _and_kernel, "bitmap_vm": _vm_kernel}

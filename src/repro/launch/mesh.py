@@ -10,19 +10,11 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.4.35: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: make_mesh has no axis_types parameter
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -26,6 +26,24 @@ def test_minhash_kernel_matches_ref(R, D, L):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("base", [0x7FFFFFF0, 0x80000000, 0xFFFFFF00])
+def test_minhash_kernel_high_hash_values(base):
+    """Hashes at or above 0x80000000 (the kernel takes its min in the signed
+    domain): rows straddle the sign bit, sit wholly above it, or wrap."""
+    rng = np.random.default_rng(base % 1000)
+    vers = rng.integers(0, 64, size=(256, 128)).astype(np.int32)
+    vers[rng.random((256, 128)) < 0.3] = -1
+    a = np.array([1, 3, 0x9E3779B1], dtype=np.uint32)
+    b = np.full(3, base, dtype=np.uint32)
+    got = kminhash.minhash(jnp.asarray(vers), jnp.asarray(a), jnp.asarray(b),
+                           interpret=True)
+    want = ref.minhash_ref(jnp.asarray(vers), jnp.asarray(a), jnp.asarray(b))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    hv = (a[:, None, None].astype(np.uint64) * vers[None].astype(np.uint64)
+          + base) % 2**32
+    assert (hv[:, vers >= 0] >= 0x80000000).any()
+
+
 def test_minhash_empty_rows_are_maxval():
     vers = np.full((128, 128), -1, dtype=np.int32)
     a, b = ops.hash_family(3)
@@ -67,6 +85,20 @@ def test_xor_delta_kernel_matches_ref(N, W):
     rng = np.random.default_rng(N + W)
     p = rng.integers(0, 2**32, size=(N, W), dtype=np.uint32)
     c = rng.integers(0, 2**32, size=(N, W), dtype=np.uint32)
+    d, cnt = kdelta.xor_delta(jnp.asarray(p), jnp.asarray(c), interpret=True)
+    dr, cr = ref.xor_delta_ref(jnp.asarray(p), jnp.asarray(c))
+    np.testing.assert_array_equal(np.asarray(d), np.asarray(dr))
+    np.testing.assert_array_equal(np.asarray(cnt), np.asarray(cr))
+
+
+@pytest.mark.parametrize("N,W,block_w", [(128, 384, 128), (256, 1024, 256)])
+def test_xor_delta_kernel_multi_block_matches_ref(N, W, block_w, monkeypatch):
+    """Tiled along W: changed-word counts add up across the lane blocks."""
+    monkeypatch.setattr(kdelta, "_TILE_BYTES", kdelta.BLOCK_N * block_w * 4)
+    rng = np.random.default_rng(N + W + block_w)
+    p = rng.integers(0, 2**32, size=(N, W), dtype=np.uint32)
+    c = p.copy()
+    c[rng.random((N, W)) < 0.3] ^= np.uint32(1)
     d, cnt = kdelta.xor_delta(jnp.asarray(p), jnp.asarray(c), interpret=True)
     dr, cr = ref.xor_delta_ref(jnp.asarray(p), jnp.asarray(c))
     np.testing.assert_array_equal(np.asarray(d), np.asarray(dr))
@@ -143,6 +175,33 @@ def test_bitmap_vm_kernel_matches_ref(S, W, P):
     o2, c2 = ref.bitmap_vm_ref(jnp.asarray(regs), jnp.asarray(prog))
     np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
     np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
+
+
+@pytest.mark.parametrize("S,W,P,block_w", [(128, 384, 16, 128),
+                                            (256, 512, 40, 256)])
+def test_bitmap_vm_kernel_multi_block_matches_ref(S, W, P, block_w,
+                                                  monkeypatch):
+    """Tiled along W: every lane block runs the whole program and the
+    popcounts add up across blocks."""
+    monkeypatch.setattr(kbitmap, "_VM_TILE_BYTES", S * block_w * 4)
+    rng = np.random.default_rng(S + W + P + block_w)
+    regs = rng.integers(0, 2**32, size=(S, W), dtype=np.uint32)
+    prog = _random_prog(rng, S, P)
+    o1, c1 = kbitmap.bitmap_vm(jnp.asarray(regs), jnp.asarray(prog),
+                               interpret=True)
+    o2, c2 = ref.bitmap_vm_ref(jnp.asarray(regs), jnp.asarray(prog))
+    np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
+    np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
+
+
+@pytest.mark.parametrize("width,rows,budget,want", [
+    (128, 4096, 1, 128),            # never below one lane tile
+    (8192, 256, 2 << 20, 2048),
+    (384, 8, 2 << 20, 384),
+    (1152, 128, 256 << 10, 384),    # widest divisor within budget
+])
+def test_lane_block(width, rows, budget, want):
+    assert kbitmap._lane_block(width, rows, budget) == want
 
 
 @pytest.mark.parametrize("op", [kbitmap.OP_AND, kbitmap.OP_OR, kbitmap.OP_ANDNOT])
