@@ -32,9 +32,16 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent / "src"))
 
+# The smoke's sizes.  The phase functions take them as arguments, so tests
+# can rehearse the phases on the CPU at a tiny size.
+BASE_RECORDS = 20_000         # the B1 store; the shingle store gets a tenth
 N_VERSIONS = 1001
 RECORD_BYTES = 1024
 ATTRS = ("f0", "f1")
+N_WAVES = 4
+WAVE_SIZE = 64
+N_COMMITS = 4                 # versions committed under load
+MIN_STORED_BYTES = 1 << 30    # chunk data both one-chip stores must hold
 
 
 def log(msg: str) -> None:
@@ -186,7 +193,7 @@ def device_tables(kvs) -> List[object]:
 def check_placement(kvs, platform: str) -> None:
     """Every slot table of the backend is an array on ``platform``."""
     for t in device_tables(kvs):
-        plats = {d.platform for d in t.devices}
+        plats = {d.platform for d in t.synced_devices()}
         if plats != {platform}:
             raise AssertionError(f"device table placed on {plats}, "
                                  f"not {platform}")
@@ -328,7 +335,8 @@ def store_report(rs, label: str, times: Dict[str, float]) -> int:
     log(f"{label}: {st['n_chunks']} chunks, {st['stored_chunk_bytes']} "
         f"stored chunk bytes, {st['raw_unique_bytes']} raw record bytes, "
         f"{table_bytes} device-table bytes in {len(tables)} tables")
-    devices = sorted({d for t in tables for d in t.devices}, key=lambda d: d.id)
+    devices = sorted({d for t in tables for d in t.synced_devices()},
+                     key=lambda d: d.id)
     log(f"{label}: device bytes in use with the store loaded: " + ", ".join(
         f"chip {d.id} {(d.memory_stats() or {}).get('bytes_in_use')}"
         for d in devices))
@@ -338,8 +346,9 @@ def store_report(rs, label: str, times: Dict[str, float]) -> int:
 
 
 # ------------------------------------------------------------------- phases
-def one_chip(platform: str, seed: int, base_records: int, n_versions: int,
-             n_waves: int, wave_size: int, n_commits: int) -> int:
+def one_chip(platform: str, seed: int, base_records: int = BASE_RECORDS,
+             n_versions: int = N_VERSIONS, n_waves: int = N_WAVES,
+             wave_size: int = WAVE_SIZE, n_commits: int = N_COMMITS) -> int:
     """The ~1 GB bottom-up store (with writes under load) and the
     tenth-size shingle k=3 store, each on 4 device-table shards.  Returns
     the stored chunk bytes of both."""
@@ -365,8 +374,9 @@ def one_chip(platform: str, seed: int, base_records: int, n_versions: int,
     return stored
 
 
-def four_chips(devices, seed: int, base_records: int, n_versions: int,
-               n_waves: int, wave_size: int) -> int:
+def four_chips(devices, seed: int, base_records: int = BASE_RECORDS,
+               n_versions: int = N_VERSIONS, n_waves: int = N_WAVES,
+               wave_size: int = WAVE_SIZE) -> int:
     """The bottom-up store on 4 shards x 2 replicas over a mesh of four
     chips: tables must span every chip, and the replicas of each shard
     must sit on different chips."""
@@ -384,7 +394,7 @@ def four_chips(devices, seed: int, base_records: int, n_versions: int,
     check_placement(kvs, devices[0].platform)
     used = set()
     for i, group in enumerate(kvs.shards):
-        chips = [t.devices for t in group.replicas]
+        chips = [t.synced_devices() for t in group.replicas]
         used.update(d for c in chips for d in c)
         if any(a & b for j, a in enumerate(chips) for b in chips[j + 1:]):
             raise AssertionError(f"shard {i} has replicas sharing a chip")
@@ -400,7 +410,7 @@ def four_chips(devices, seed: int, base_records: int, n_versions: int,
 
 def kernel_report() -> None:
     """Each kernel on the path: its lowered TPU program holds a Mosaic
-    ``tpu_custom_call``, and the run compiled it for at least one shape."""
+    ``tpu_custom_call``, and the run launched it at least once."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import ops
@@ -414,9 +424,9 @@ def kernel_report() -> None:
     for name, args in args.items():
         fn = ops.KERNELS[name]
         custom = "tpu_custom_call" in fn.lower(*args).as_text()
-        used = fn._cache_size()
+        used = ops.KERNEL_LAUNCHES[name]
         log(f"kernel {name}: lowered program holds tpu_custom_call: "
-            f"{custom}; shapes compiled by this run: {used}")
+            f"{custom}; launches in this run: {used}")
         if not custom or used == 0:
             raise AssertionError(f"kernel {name} did not run on the chip")
 
@@ -431,14 +441,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--base-records", type=int, default=20_000,
-                    help="base records of the bottom-up store (the shingle "
-                         "store gets a tenth)")
-    ap.add_argument("--versions", type=int, default=N_VERSIONS)
-    ap.add_argument("--waves", type=int, default=4)
-    ap.add_argument("--wave-size", type=int, default=64)
-    ap.add_argument("--commits", type=int, default=4,
-                    help="versions committed under load")
     args = ap.parse_args(argv)
 
     import jax
@@ -460,12 +462,12 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     t0 = time.perf_counter()
     try:
         if args.chips == 4:
-            stored = four_chips(devices, args.seed, args.base_records,
-                                args.versions, args.waves, args.wave_size)
+            stored = four_chips(devices, args.seed)
         else:
-            stored = one_chip(dev.platform, args.seed, args.base_records,
-                              args.versions, args.waves, args.wave_size,
-                              args.commits)
+            stored = one_chip(dev.platform, args.seed)
+            if stored < MIN_STORED_BYTES:
+                raise AssertionError(f"the stores hold {stored} bytes of "
+                                     f"chunk data, under {MIN_STORED_BYTES}")
             kernel_report()
         peak_memory_report(devices[:args.chips])
     except Exception:  # noqa: BLE001 - any failed phase fails the smoke

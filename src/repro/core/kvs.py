@@ -455,9 +455,9 @@ class ShardedDeviceKVS:
             self._dirty = False
         return self._table
 
-    @property
-    def devices(self) -> set:
-        """The devices the slot table is placed on (syncs it first)."""
+    def synced_devices(self) -> set:
+        """Upload the slot table if the host mirror is dirty, and return the
+        devices it is placed on."""
         return set(self._sync().devices())
 
     # ------------------------------------------------------------------ get

@@ -13,6 +13,7 @@ fallback.
 """
 from __future__ import annotations
 
+import collections
 import functools
 from typing import Callable, Tuple
 
@@ -36,11 +37,16 @@ def _compiled(kernel: Callable) -> Callable:
     return jax.jit(functools.partial(kernel, interpret=False))
 
 
-def _pick(kernel: Callable, twin: Callable) -> Callable:
-    """The compiled kernel on TPU, its jnp twin on CPU."""
+# Compiled-kernel launches since import, by kernel name (TPU path only).
+KERNEL_LAUNCHES: collections.Counter = collections.Counter()
+
+
+def _pick(name: str, twin: Callable) -> Callable:
+    """The compiled kernel ``KERNELS[name]`` on TPU, its jnp twin on CPU."""
     platform = jax.default_backend()
     if platform == "tpu":
-        return kernel
+        KERNEL_LAUNCHES[name] += 1
+        return KERNELS[name]
     if platform == "cpu":
         return twin
     raise RuntimeError(f"no kernel path for JAX platform {platform!r}: "
@@ -69,7 +75,7 @@ def minhash_padded(versions_padded: np.ndarray, a: np.ndarray,
     Dp = _pad_to(max(D, 1), _P_LANE)
     buf = np.full((Rp, Dp), _minhash.PAD_VERSION, dtype=np.int32)
     buf[:R, :D] = versions_padded
-    out = _pick(_minhash_kernel, _minhash_twin)(
+    out = _pick("minhash", _minhash_twin)(
         jnp.asarray(buf), jnp.asarray(a), jnp.asarray(b))
     return np.asarray(out)[:, :R].T  # (R, L)
 
@@ -125,7 +131,7 @@ def xor_delta_batch(parent: np.ndarray,
     cb = np.zeros((Np, Wp), dtype=np.uint32)
     pb[:N, :W] = parent
     cb[:N, :W] = child
-    d, cnt = _pick(_xor_kernel, _xor_twin)(jnp.asarray(pb), jnp.asarray(cb))
+    d, cnt = _pick("xor_delta", _xor_twin)(jnp.asarray(pb), jnp.asarray(cb))
     return np.asarray(d)[:N, :W], np.asarray(cnt)[:N]
 
 
@@ -173,8 +179,8 @@ def and_popcount_batch(bitmaps: np.ndarray,
     rb = np.zeros((Np if pairwise else 1, Wp), dtype=np.uint32)
     bb[:N, :W] = bitmaps
     rb[:row.shape[0], :W] = row
-    anded, cnt = _pick(_and_kernel, _and_twin)(jnp.asarray(bb),
-                                               jnp.asarray(rb))
+    anded, cnt = _pick("and_popcount", _and_twin)(jnp.asarray(bb),
+                                                  jnp.asarray(rb))
     return np.asarray(anded)[:N, :W], np.asarray(cnt)[:N]
 
 
@@ -208,7 +214,7 @@ def bitmap_vm_batch(regs: np.ndarray,
     pg = np.zeros((Pp, 4), dtype=np.int32)
     pg[:, 0] = _bitmap.OP_OR           # no-op padding: regs[0] |= regs[0]
     pg[:len(prog)] = prog
-    out, cnt = _pick(_vm_kernel, _vm_twin)(jnp.asarray(rb), jnp.asarray(pg))
+    out, cnt = _pick("bitmap_vm", _vm_twin)(jnp.asarray(rb), jnp.asarray(pg))
     return np.asarray(out)[:S, :W], np.asarray(cnt)[:S]
 
 

@@ -44,22 +44,40 @@ def test_minhash_kernel_high_hash_values(base):
     assert (hv[:, vers >= 0] >= 0x80000000).any()
 
 
+def _minhash_kernel_rows(rows: np.ndarray, a, b) -> np.ndarray:
+    """The kernel (interpret=True) on rows padded to its tiles; (R, L)."""
+    R, D = rows.shape
+    buf = np.full((-(-R // kminhash.BLOCK_R) * kminhash.BLOCK_R,
+                   -(-D // 128) * 128), kminhash.PAD_VERSION, dtype=np.int32)
+    buf[:R, :D] = rows
+    out = kminhash.minhash(jnp.asarray(buf), jnp.asarray(a), jnp.asarray(b),
+                           interpret=True)
+    return np.asarray(out)[:, :R].T
+
+
 def test_minhash_empty_rows_are_maxval():
+    """An all-padding row hashes to 0xFFFFFFFF, which the kernel carries
+    through its signed-domain min as int32 max and maps back."""
     vers = np.full((128, 128), -1, dtype=np.int32)
+    vers[::2, :5] = np.arange(5)            # empty rows beside filled ones
     a, b = ops.hash_family(3)
-    out = ops.minhash_padded(vers, a, b)
-    assert (out == 0xFFFFFFFF).all()
+    for out in (_minhash_kernel_rows(vers, a, b),
+                ops.minhash_padded(vers, a, b)):
+        assert (out[1::2] == 0xFFFFFFFF).all()
+        assert (out[::2] != 0xFFFFFFFF).all()
 
 
 def test_minhash_is_permutation_invariant():
     """Min-hash of a set cannot depend on element order (the property the
-    partitioner relies on)."""
+    partitioner relies on), in the kernel and in the entry point."""
     rng = np.random.default_rng(0)
     row = rng.choice(5000, size=60, replace=False).astype(np.int32)
     a, b = ops.hash_family(8, 3)
-    m1 = ops.minhash_padded(row[None, :], a, b)
-    m2 = ops.minhash_padded(rng.permutation(row)[None, :], a, b)
+    m1 = _minhash_kernel_rows(row[None, :], a, b)
+    m2 = _minhash_kernel_rows(rng.permutation(row)[None, :], a, b)
     np.testing.assert_array_equal(m1, m2)
+    np.testing.assert_array_equal(
+        m1, ops.minhash_padded(rng.permutation(row)[None, :], a, b))
 
 
 @given(st.lists(st.lists(st.integers(0, 2**20), min_size=0, max_size=40),
@@ -262,3 +280,16 @@ def test_bitmap_vm_counts_one_launch():
     before = ops.BITMAP_LAUNCHES
     ops.bitmap_vm_batch(regs, np.zeros((0, 4), dtype=np.int32))
     assert ops.BITMAP_LAUNCHES - before == 1
+
+
+def test_cpu_runs_twins_and_other_platforms_raise(monkeypatch):
+    """On the CPU the entry points run the jnp twins and launch no compiled
+    kernel; on a platform with neither path they raise."""
+    before = dict(ops.KERNEL_LAUNCHES)
+    ops.bitmap_vm_batch(np.ones((3, 3), dtype=np.uint32),
+                        np.zeros((0, 4), dtype=np.int32))
+    ops.xor_delta_bytes(b"abcd", b"abce")
+    assert dict(ops.KERNEL_LAUNCHES) == before
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="no kernel path"):
+        ops.xor_delta_bytes(b"abcd", b"abce")
