@@ -67,6 +67,7 @@ import numpy as np
 
 from . import costmodel
 from . import plan as plan_mod
+from . import trace
 from .chunkstore import ChunkMap, StoredChunk
 from .index import Projections
 from .kvs import Backend
@@ -313,34 +314,37 @@ class Snapshot:
             # report 0 here even though their maps cost a round trip
             batch.payload_round_trips = (batch.kvs_queries
                                          if len(payload_ids) else 0)
-            for j, cid in enumerate(payload_ids):
-                cb, mb = blobs[2 * j], blobs[2 * j + 1]
-                fetched[int(cid)] = (StoredChunk.from_bytes(cb),
-                                     ChunkMap.from_bytes(mb),
-                                     len(cb) + len(mb))
-            base = 2 * len(payload_ids)
-            for j, cid in enumerate(map_only):
-                mb = blobs[base + j]
-                fetched[int(cid)] = (None, ChunkMap.from_bytes(mb), len(mb))
+            with trace.span("rstore.decode"):
+                for j, cid in enumerate(payload_ids):
+                    cb, mb = blobs[2 * j], blobs[2 * j + 1]
+                    fetched[int(cid)] = (StoredChunk.from_bytes(cb),
+                                         ChunkMap.from_bytes(mb),
+                                         len(cb) + len(mb))
+                base = 2 * len(payload_ids)
+                for j, cid in enumerate(map_only):
+                    mb = blobs[base + j]
+                    fetched[int(cid)] = (None, ChunkMap.from_bytes(mb),
+                                         len(mb))
 
         ctx = self._exec_context(fetched)
         results: List[QueryResult] = []
-        for pq in planned:
-            stats = QueryStats(
-                chunks_fetched=len(pq.cand),
-                bytes_fetched=sum(fetched[int(c)][2] for c in pq.cand),
-                kvs_queries=batch.kvs_queries if len(pq.cand) else 0,
-                payload_chunks_fetched=(len(pq.cand) if pq.needs_payload
-                                        else 0),
-                payload_round_trips=(batch.payload_round_trips
-                                     if pq.needs_payload and len(pq.cand)
-                                     else 0),
-            )
-            value = plan_mod.answer(pq, ctx, stats)
-            batch.records_returned += stats.records_returned
-            batch.irrelevant_chunks += stats.irrelevant_chunks
-            results.append(QueryResult(query=pq.query, value=value,
-                                       stats=stats))
+        with trace.span("rstore.answer"):
+            for pq in planned:
+                stats = QueryStats(
+                    chunks_fetched=len(pq.cand),
+                    bytes_fetched=sum(fetched[int(c)][2] for c in pq.cand),
+                    kvs_queries=batch.kvs_queries if len(pq.cand) else 0,
+                    payload_chunks_fetched=(len(pq.cand) if pq.needs_payload
+                                            else 0),
+                    payload_round_trips=(batch.payload_round_trips
+                                         if pq.needs_payload and len(pq.cand)
+                                         else 0),
+                )
+                value = plan_mod.answer(pq, ctx, stats)
+                batch.records_returned += stats.records_returned
+                batch.irrelevant_chunks += stats.irrelevant_chunks
+                results.append(QueryResult(query=pq.query, value=value,
+                                           stats=stats))
         return BatchResult(results, batch)
 
     def _exec_context(self, fetched: Dict[int, Tuple[Optional[StoredChunk],

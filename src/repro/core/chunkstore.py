@@ -18,6 +18,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..kernels import ops as kops
+from . import trace
 from .types import Partitioning
 from .version_graph import VersionGraph
 
@@ -92,6 +93,10 @@ class StoredChunk:
 
     def payloads(self) -> Dict[int, bytes]:
         """Decode every record: local index -> payload bytes."""
+        with trace.span("rstore.decode"):
+            return self._payloads()
+
+    def _payloads(self) -> Dict[int, bytes]:
         out: Dict[int, bytes] = {}
         for sc in self.subchunks:
             raw = zlib.decompress(sc.blob)

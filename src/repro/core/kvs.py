@@ -49,6 +49,7 @@ from typing import Dict, Iterable, List, Optional, Protocol, Sequence, Tuple
 
 import numpy as np
 
+from . import trace
 from .costmodel import BANDWIDTH_BPS, PER_QUERY_S
 
 
@@ -357,7 +358,12 @@ class ShardedDeviceKVS:
         self._free: List[Tuple[int, int]] = []   # (slot, n) reclaimed extents
         self._dir: Dict[str, Tuple[int, int, int]] = {}  # key -> (slot, n, len)
         self.stats = KVSStats()
-        self._gather = jax.jit(lambda t, idx: jnp.take(t, idx, axis=0))
+
+        # one jitted function per table, so each table keeps its own
+        # compiled programs (one per index length, ROADMAP S4)
+        def gather_rows(t, idx):
+            return jnp.take(t, idx, axis=0)
+        self._gather = jax.jit(gather_rows)
 
     # ------------------------------------------------------------------ put
     def put(self, key: str, value: bytes) -> None:
@@ -464,15 +470,19 @@ class ShardedDeviceKVS:
     def multiget(self, keys: Sequence[str]) -> List[bytes]:
         if not keys:                      # empty batch: no gather, no stats
             return []
-        table = self._sync()
-        metas = [self._dir[k] for k in keys]
-        idx = np.concatenate([np.arange(s, s + n) for s, n, _ in metas])
-        rows = np.asarray(self._gather(table, self._jnp.asarray(idx)))
-        out: List[bytes] = []
-        off = 0
-        for _, n, ln in metas:
-            out.append(rows[off:off + n].tobytes()[:ln])
-            off += n
+        with trace.span("rstore.gather") as sp:
+            table = self._sync()
+            metas = [self._dir[k] for k in keys]
+            idx = np.concatenate([np.arange(s, s + n) for s, n, _ in metas])
+            # a call that grows the jit's cache compiled (or loaded) a program
+            cached = self._gather._cache_size()
+            rows = np.asarray(self._gather(table, self._jnp.asarray(idx)))
+            sp.counts["new_length"] = int(self._gather._cache_size() > cached)
+            out: List[bytes] = []
+            off = 0
+            for _, n, ln in metas:
+                out.append(rows[off:off + n].tobytes()[:ln])
+                off += n
         self.stats.n_queries += 1
         self.stats.n_values += len(keys)
         self.stats.bytes_fetched += int(rows.nbytes)

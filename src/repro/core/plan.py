@@ -50,6 +50,7 @@ import numpy as np
 
 from ..kernels import bitmap as kbitmap
 from ..kernels import ops as kops
+from . import trace
 from .index import Projections, _bitmap_to_ids
 from .types import unpack_ck
 
@@ -429,6 +430,10 @@ class Planner:
     def plan_batch(self, queries: Sequence[Query]) -> List[PlannedQuery]:
         """One-shot: compile the whole batch, run (at most) ONE fused
         bitmap-program launch, return the physical plans."""
+        with trace.span("rstore.plan"):
+            return self._plan_batch(queries)
+
+    def _plan_batch(self, queries: Sequence[Query]) -> List[PlannedQuery]:
         planned: List[PlannedQuery] = []
         # (position in `planned`, root register) per launch-dependent query
         pending_roots: List[Tuple[int, int]] = []

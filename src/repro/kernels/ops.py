@@ -34,7 +34,13 @@ def _pad_to(x: int, m: int) -> int:
 
 
 def _compiled(kernel: Callable) -> Callable:
-    return jax.jit(functools.partial(kernel, interpret=False))
+    """``kernel`` jitted, compiled for the chip, under its own name and
+    parameter names: the trace's module is ``jit_<kernel>`` and its
+    operands keep the kernel's argument names."""
+    @functools.wraps(kernel)
+    def run(*args):
+        return kernel(*args, interpret=False)
+    return jax.jit(run)
 
 
 # Compiled-kernel launches since import, by kernel name (TPU path only).

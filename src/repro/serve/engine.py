@@ -19,6 +19,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..core import trace
 from ..models.config import ModelConfig
 from ..models.model import Model, build_model
 
@@ -30,14 +31,13 @@ class StoreQueryEngine:
     planning, kernel launches and the KVS multiget are batched per wave by
     the planner, not per query.  A full ``build()`` under the engine
     invalidates the pin and the next wave re-snapshots; a compaction pass
-    just re-pins via ``snapshot.refresh()``.
+    just re-pins via ``snapshot.refresh()``.  Each wave is one
+    ``rstore.serve`` wave in :data:`repro.core.trace.WAVES`.
     """
 
     def __init__(self, rs) -> None:
         self.rs = rs
         self._snap = None
-        self.waves_served = 0
-        self.repins = 0
 
     def snapshot(self):
         """The current pinned snapshot (taken lazily, kept across waves)."""
@@ -55,14 +55,13 @@ class StoreQueryEngine:
             except RuntimeError:
                 snap = self.rs.snapshot()      # full rebuild: new snapshot
             self._snap = snap
-            self.repins += 1
         return snap
 
     def serve(self, queries: Sequence[Any]):
         """Execute one wave → :class:`~repro.core.plan.BatchResult`."""
-        batch = self._fresh_snapshot().execute(list(queries))
-        self.waves_served += 1
-        return batch
+        queries = list(queries)
+        with trace.wave("rstore.serve", queries=len(queries)):
+            return self._fresh_snapshot().execute(queries)
 
     def explain(self, queries: Sequence[Any]) -> List[Dict[str, Any]]:
         """Rendered plans + predicted costs for a wave (no execution)."""

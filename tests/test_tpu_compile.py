@@ -81,3 +81,27 @@ def test_device_table_gather_compiles(one_chip):
     mem = c.memory_analysis()
     assert mem.argument_size_in_bytes >= 1 << 30
     assert mem.output_size_in_bytes == 512 * (1 << 16)
+
+
+@pytest.mark.parametrize("name,shapes,params", [
+    ("bitmap_vm", [((128, 128), jnp.uint32), ((8, 4), jnp.int32)],
+     ("regs", "prog")),
+    ("xor_delta", [((128, 128), jnp.uint32), ((128, 128), jnp.uint32)],
+     ("parent", "child")),
+])
+def test_kernel_programs_are_named_by_kernel(one_chip, name, shapes, params):
+    # the trace names a program by its module and an op's operands by the
+    # kernel's parameters (the XOR-delta kernel is found by its operands)
+    from repro.kernels import ops
+    low = ops.KERNELS[name].lower(*(_sds(s, d, one_chip) for s, d in shapes))
+    assert f"module @jit_{name} " in low.as_text()
+    hlo = low.as_text(dialect="hlo")
+    assert all(f"{p}.1 = " in hlo for p in params)
+
+
+def test_device_table_gather_is_named(one_chip):
+    from repro.core import ShardedDeviceKVS
+    kvs = ShardedDeviceKVS(slot_bytes=512, n_slots=8)
+    low = kvs._gather.lower(_sds((8, 128), jnp.uint32, one_chip),
+                            _sds((4,), jnp.int32, one_chip))
+    assert "module @jit_gather_rows " in low.as_text()
