@@ -15,8 +15,9 @@ Three implementations:
 
 - :class:`ShardedDeviceKVS` — the TPU-native realization: a fixed-slot
   ``uint32[n_slots, slot_words]`` table sharded across the JAX mesh's
-  devices; ``multiget`` is ONE jitted batched gather (the gather's collective
-  traffic scales with span, which the roofline section measures).
+  devices; ``multiget`` is one round trip of jitted batched gathers on a
+  fixed ladder of index lengths (the gather's collective traffic scales
+  with span, which the roofline section measures).
 
 - :class:`ShardedKVS` — the *distributed* layer the paper assumes: a router
   that hash-partitions the keyspace over N inner backends and fans
@@ -47,10 +48,34 @@ import zlib
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Protocol, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from . import trace
 from .costmodel import BANDWIDTH_BPS, PER_QUERY_S
+
+# A gather's index length is rounded up to a power of two, at least this
+# many rows, so a run meets a handful of programs instead of one per length.
+GATHER_MIN_ROWS = 8
+# Longer indexes are gathered in blocks of this many rows (16 MiB of 64 KiB
+# slots): it bounds both the set of programs and the output live on device.
+GATHER_BLOCK_ROWS = 256
+# Every length a gather runs at: GATHER_MIN_ROWS, twice that, ... up to a block.
+GATHER_LADDER = tuple(1 << i for i in range(GATHER_MIN_ROWS.bit_length() - 1,
+                                            GATHER_BLOCK_ROWS.bit_length()))
+
+
+@jax.jit
+def gather_rows(t, idx):
+    """The device-table gather; jit keys its programs by the table's shape
+    and sharding and the index length, so tables of one shape share them."""
+    return jnp.take(t, idx, axis=0)
+
+
+def gather_bucket(n: int) -> int:
+    """The index length a gather of ``n`` rows (at most a block) runs at."""
+    return max(GATHER_MIN_ROWS, 1 << (n - 1).bit_length())
 
 
 @dataclass
@@ -333,21 +358,21 @@ class ShardedDeviceKVS:
     """Fixed-slot store living as a device-sharded JAX array.
 
     Values are padded into ``slot_bytes`` slots; longer values span
-    consecutive slots.  ``multiget`` issues a single ``jnp.take`` over the
-    sharded table — on a real mesh this is a batched all-gather whose volume
-    is span × slot size.  Host-side writes are buffered and flushed in one
-    device_put; ``multiput`` stages a whole group commit as one write round
-    trip (ingest is batched, mirroring §4's delta store).  Freed extents
-    (relocated or shrunk values) go on a first-fit free list so overwrites
-    never leak slots.
+    consecutive slots.  ``multiget`` gathers the keys' slot rows with the
+    process-wide jitted :func:`gather_rows` over the sharded table, in
+    blocks of at most ``GATHER_BLOCK_ROWS`` rows, each padded to a
+    power-of-two length (:func:`gather_bucket`), so every table of one shape
+    shares a handful of compiled programs (``GATHER_LADDER``), all run when a
+    table of a new shape is uploaded.  On a real mesh each block is a
+    batched all-gather whose volume is span × slot size.  Host-side writes
+    are buffered and flushed in one device_put; ``multiput`` stages a whole
+    group commit as one write round trip (ingest is batched, mirroring §4's
+    delta store).  Freed extents (relocated or shrunk values) go on a
+    first-fit free list so overwrites never leak slots.
     """
 
     def __init__(self, slot_bytes: int = 1 << 16, n_slots: int = 1024,
                  mesh=None) -> None:
-        import jax
-        import jax.numpy as jnp
-        self._jax = jax
-        self._jnp = jnp
         self.slot_bytes = int(slot_bytes)
         self.slot_words = self.slot_bytes // 4
         self.mesh = mesh
@@ -358,12 +383,8 @@ class ShardedDeviceKVS:
         self._free: List[Tuple[int, int]] = []   # (slot, n) reclaimed extents
         self._dir: Dict[str, Tuple[int, int, int]] = {}  # key -> (slot, n, len)
         self.stats = KVSStats()
-
-        # one jitted function per table, so each table keeps its own
-        # compiled programs (one per index length, ROADMAP S4)
-        def gather_rows(t, idx):
-            return jnp.take(t, idx, axis=0)
-        self._gather = jax.jit(gather_rows)
+        self._gather = gather_rows
+        self._ladder_shape = None                # table shape the ladder ran on
 
     # ------------------------------------------------------------------ put
     def put(self, key: str, value: bytes) -> None:
@@ -448,17 +469,25 @@ class ShardedDeviceKVS:
 
     def _sync(self):
         if self._dirty or self._table is None:
-            jnp = self._jnp
             if self.mesh is not None:
                 from jax.sharding import NamedSharding, PartitionSpec as P
                 ndev = math.prod(self.mesh.devices.shape)
                 pad = (-len(self._host)) % ndev
                 host = np.pad(self._host, ((0, pad), (0, 0)))
                 sh = NamedSharding(self.mesh, P(tuple(self.mesh.axis_names), None))
-                self._table = self._jax.device_put(host, sh)
+                self._table = jax.device_put(host, sh)
             else:
                 self._table = jnp.asarray(self._host)
             self._dirty = False
+            if self._table.shape != self._ladder_shape:
+                # a new table shape runs the whole ladder at upload, so no
+                # later read compiles a bucket; tables of one shape share it.
+                # The index goes in as a device array, as a read's does: jit
+                # caches numpy and device arguments apart
+                self._ladder_shape = self._table.shape
+                for n in GATHER_LADDER:
+                    idx = jnp.asarray(np.zeros(n, np.int32))
+                    self._gather(self._table, idx).block_until_ready()
         return self._table
 
     def synced_devices(self) -> set:
@@ -471,13 +500,25 @@ class ShardedDeviceKVS:
         if not keys:                      # empty batch: no gather, no stats
             return []
         with trace.span("rstore.gather") as sp:
+            # a call that grows the jit's cache compiled (or loaded) a program
+            cached = self._gather._cache_size()
             table = self._sync()
             metas = [self._dir[k] for k in keys]
             idx = np.concatenate([np.arange(s, s + n) for s, n, _ in metas])
-            # a call that grows the jit's cache compiled (or loaded) a program
-            cached = self._gather._cache_size()
-            rows = np.asarray(self._gather(table, self._jnp.asarray(idx)))
+            rows = np.empty((len(idx), self.slot_words), dtype=np.uint32)
+            pad_rows = 0
+            for lo in range(0, len(idx), GATHER_BLOCK_ROWS):
+                block = idx[lo:lo + GATHER_BLOCK_ROWS]
+                k = len(block)
+                padded = np.full(gather_bucket(k), block[0], dtype=np.int32)
+                padded[:k] = block
+                pad_rows += len(padded) - k
+                # to the host before the next block is issued: one block's
+                # output at most is live on the device
+                rows[lo:lo + k] = np.asarray(
+                    self._gather(table, jnp.asarray(padded)))[:k]
             sp.counts["new_length"] = int(self._gather._cache_size() > cached)
+            sp.counts["pad_bytes"] = pad_rows * self.slot_bytes
             out: List[bytes] = []
             off = 0
             for _, n, ln in metas:

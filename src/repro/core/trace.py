@@ -20,9 +20,11 @@ before exit):
 - ``rstore.serve``: ``queries``, the wave's size: the wave's root;
 - ``rstore.plan``: the planner, register file staging and the bitmap-VM
   launch;
-- ``rstore.gather``: ``new_length``: one device table's multiget;
-  ``new_length`` is 1 where the call grew the table's gather jit cache,
-  i.e. compiled (or loaded) a program for a new index length;
+- ``rstore.gather``: ``new_length``, ``pad_bytes``: one device table's
+  multiget; ``new_length`` is 1 where the call grew the process's
+  ``gather_rows`` jit cache, i.e. compiled (or loaded) a program for a new
+  (table shape, bucketed index length); ``pad_bytes`` counts the rows
+  gathered only to pad the index up to its bucket;
 - ``rstore.decode``: chunk and chunk-map decoding (zlib and XOR delta);
 - ``rstore.answer``: the answer step over the fetched chunks.
 """

@@ -72,15 +72,18 @@ def test_minhash_compiles(one_chip):
 
 
 def test_device_table_gather_compiles(one_chip):
-    # the ShardedDeviceKVS multiget: jnp.take over a 1 GiB uint32 slot table
+    # the ShardedDeviceKVS multiget's largest block over a 1 GiB uint32 slot
+    # table: its output, the most a gather holds on the device, is 16 MiB
+    from repro.core import kvs
     slot_words = (1 << 16) // 4
     n_slots = (1 << 30) // (1 << 16)
-    c = _compile(lambda t, i: jnp.take(t, i, axis=0),
+    block = kvs.GATHER_BLOCK_ROWS
+    c = _compile(kvs.gather_rows,
                  _sds((n_slots, slot_words), jnp.uint32, one_chip),
-                 _sds((512,), jnp.int32, one_chip))
+                 _sds((block,), jnp.int32, one_chip))
     mem = c.memory_analysis()
     assert mem.argument_size_in_bytes >= 1 << 30
-    assert mem.output_size_in_bytes == 512 * (1 << 16)
+    assert mem.output_size_in_bytes == block * (1 << 16) == 16 << 20
 
 
 @pytest.mark.parametrize("name,shapes,params", [
